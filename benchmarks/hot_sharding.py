@@ -29,11 +29,10 @@ def run(f: int = 1 << 16, p: int = 64, n: int = 1 << 15,
     mean = max(1, uniq // p)
     cap = max(16, int(cap_factor * mean))
 
-    counts = hot_sharding.feature_counts(ids, f)
     rows = []
     for max_hot in (0, 16, 64, 256, 1024):
         if max_hot:
-            hot = hot_sharding.select_hot(counts, 1e-4, max_hot)
+            hot = jnp.asarray(hot_sharding.select_hot(ids_np, 1e-4, max_hot))
             _, is_hot, cold = hot_sharding.split_hot(ids, hot)
             n_hot = int(jnp.sum(is_hot))
         else:

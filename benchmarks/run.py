@@ -155,14 +155,12 @@ def bench_train_step():
     from repro.models import registry
     from repro.train import trainer
 
-    from repro import compat
-
     mesh = make_host_mesh(1, 1)
     cfg = registry.smoke_config("granite-8b")
     spec = registry.get_spec("granite-8b")
     tc = TrainConfig()
     pc = ParallelConfig()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = trainer.init_state(spec, cfg, tc, pc, jax.random.PRNGKey(0))
         step = jax.jit(trainer.make_train_step(spec, cfg, tc, pc, mesh))
         src = get_source("lm_markov", vocab_size=cfg.vocab_size, seq_len=64,

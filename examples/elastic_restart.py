@@ -14,7 +14,6 @@ import tempfile
 
 import jax
 
-from repro import compat
 from repro.launch.train import build_parser, train_loop
 from repro.runtime.fault_tolerance import FailureInjector, run_with_restarts
 
@@ -43,7 +42,7 @@ cfg = registry.smoke_config("granite-8b")
 spec = registry.get_spec("granite-8b")
 tc = TrainConfig()
 pc = ParallelConfig()
-with compat.set_mesh(mesh2):
+with jax.set_mesh(mesh2):
     like = trainer.init_state(spec, cfg, tc, pc, jax.random.PRNGKey(0))
     sdefs = trainer.state_defs(spec, cfg, tc, pc)
     shardings = trainer.shardings_for_state(sdefs, mesh2)

@@ -15,7 +15,6 @@ import time
 import jax
 import numpy as np
 
-from repro import compat
 from repro.configs.base import ParallelConfig, TrainConfig
 from repro.data import ShardedLoader, get_source
 from repro.launch.mesh import make_host_mesh
@@ -48,7 +47,7 @@ def main():
                        encdec_d_model=(cfg.d_model if cfg.family == "encdec"
                                        else 0)),
             mesh, placement="device", prefetch=0)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = trainer.init_state(spec, cfg,
                                        TrainConfig(optimizer="sgd"),
                                        ParallelConfig(), jax.random.PRNGKey(1))

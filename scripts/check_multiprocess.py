@@ -43,6 +43,9 @@ def _run(extra: list[str], timeout: int = 600) -> subprocess.Popen:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     env.pop("XLA_FLAGS", None)       # --local-devices owns the device count
+    # a CPU emulation of multi-host: the children never reach for a chip
+    # (on a TPU machine two of them would each try to take every chip)
+    env["JAX_PLATFORMS"] = "cpu"
     return subprocess.Popen(
         [sys.executable, "-m", "repro.launch.train", *COMMON, *extra],
         env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)

@@ -2,11 +2,12 @@
 
 Every strategy method runs inside shard_map, so its collectives name mesh
 axes (`jax.lax.all_to_all(x, ctx.axes, ...)`). To trace those bodies
-WITHOUT devices we extend jax's axis environment with the analytic axis
-sizes (`jax.core.extend_axis_env_nd`) and run `jax.make_jaxpr` on abstract
-inputs — the jaxpr then records each collective primitive with its axis
-names, operand shapes, and dtypes, for any geometry (a 512-chip two-pod
-mesh traces fine on a CPU-only host).
+WITHOUT devices we wrap them in `jax.shard_map` over a
+`jax.sharding.AbstractMesh` of the analytic axis sizes (an abstract mesh
+names axes and sizes but holds no devices) and run `jax.make_jaxpr` on
+abstract inputs — the body jaxpr then records each collective primitive
+with its axis names, operand shapes, and dtypes, for any geometry (a
+512-chip two-pod mesh traces fine on a CPU-only host).
 
 `trace_strategy` produces the auditor's raw material: the collective list
 of `distribute`, of the carry-advancing `reduce` path (SGD), and — for
@@ -22,6 +23,8 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, Jaxpr
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 # collectives the wire model understands (see wire.py); anything else that
 # smells like a collective is still EXTRACTED so the auditor can reject it
@@ -80,40 +83,51 @@ def _axis_tuple(axis_name) -> tuple[str, ...]:
     return (str(axis_name),)
 
 
-def trace_jaxpr(fn, axis_sizes: dict, *avals):
-    """`jax.make_jaxpr(fn)(*avals)` under an analytic axis environment.
+def _per_device(fn, axis_sizes: dict):
+    """`fn` as the body of a shard_map over an abstract mesh of
+    `axis_sizes`. Every input and output is replicated (`P()`), so the
+    body sees exactly the avals it is given, as one device would."""
+    mesh = AbstractMesh(tuple(int(v) for v in axis_sizes.values()),
+                        tuple(axis_sizes))
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
 
-    `axis_sizes` maps mesh axis name -> size; the environment makes
+
+def trace_jaxpr(fn, axis_sizes: dict, *avals) -> ClosedJaxpr:
+    """The per-device jaxpr of `fn(*avals)` on an analytic mesh.
+
+    `axis_sizes` maps mesh axis name -> size; the abstract mesh makes
     `axis_index` / `all_to_all` / ... traceable without any devices.
-    `avals` are `jax.ShapeDtypeStruct` pytrees.
+    `avals` are `jax.ShapeDtypeStruct` pytrees. Returns the shard_map
+    BODY, so its invars/outvars are `fn`'s own arguments and results.
     """
-    with jax.core.extend_axis_env_nd(tuple(axis_sizes.items())):
-        return jax.make_jaxpr(fn)(*avals)
+    outer = jax.make_jaxpr(_per_device(fn, axis_sizes))(*avals)
+    (eqn,) = outer.jaxpr.eqns
+    return ClosedJaxpr(eqn.params["jaxpr"], ())
 
 
 def _eval_shape(fn, axis_sizes: dict, *avals):
-    with jax.core.extend_axis_env_nd(tuple(axis_sizes.items())):
-        return jax.eval_shape(fn, *avals)
+    return jax.eval_shape(_per_device(fn, axis_sizes), *avals)
 
 
 def _subjaxprs(eqn) -> Iterable:
     for v in eqn.params.values():
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, ClosedJaxpr):
             yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, Jaxpr):
             yield v
         elif isinstance(v, (tuple, list)):
             for x in v:
-                if isinstance(x, jax.core.ClosedJaxpr):
+                if isinstance(x, ClosedJaxpr):
                     yield x.jaxpr
-                elif isinstance(x, jax.core.Jaxpr):
+                elif isinstance(x, Jaxpr):
                     yield x
 
 
 def collect_collectives(jaxpr) -> list[Collective]:
     """Recursively extract collective eqns (incl. pjit/scan/shard_map
     sub-jaxprs) from a Jaxpr or ClosedJaxpr."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     out: list[Collective] = []
 
@@ -201,8 +215,7 @@ def trace_strategy(strategy, ctx, axis_sizes: dict,
     carry_aval = None
     stateful = False
     carry_1d_f32 = None
-    with jax.core.extend_axis_env_nd(tuple(axis_sizes.items())):
-        carry0 = strategy.init_carry(ctx)
+    carry0 = jax.eval_shape(lambda: strategy.init_carry(ctx))
     if carry0 is not None:
         stateful = True
         carry_aval = jax.ShapeDtypeStruct(tuple(carry0.shape),

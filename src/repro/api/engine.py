@@ -45,7 +45,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.api.strategies import list_strategies
 from repro.ckpt.checkpointer import Checkpointer
 from repro.configs.base import DPMRConfig
@@ -98,14 +97,12 @@ def binary_prf_metrics(predict_fn: Callable[[dict], np.ndarray],
 
 
 def hot_ids_from_corpus(cfg: DPMRConfig, sample_batches: Iterable[dict],
-                        mesh) -> jax.Array:
-    """initParameters-time frequency statistics -> replicated hot set."""
-    f = dpmr.padded_features(cfg, mesh)
-    counts = jnp.zeros((f,), jnp.int32)
-    for b in sample_batches:
-        counts = counts + hot_sharding.feature_counts(
-            jnp.asarray(b["ids"]), f)
-    return hot_sharding.select_hot(counts, cfg.hot_threshold, cfg.max_hot)
+                        mesh) -> np.ndarray:
+    """initParameters-time frequency statistics -> the hot set, counted on
+    the host over the sample's ids (no (F,) histogram on a device)."""
+    ids = np.concatenate([np.asarray(b["ids"]).reshape(-1)
+                          for b in sample_batches])
+    return hot_sharding.select_hot(ids, cfg.hot_threshold, cfg.max_hot)
 
 
 class DPMREngine:
@@ -147,7 +144,7 @@ class DPMREngine:
         self._checkpointers: dict[str, Checkpointer] = {}
         self._loader: ShardedLoader | None = None
         self._schedule = dpmr.make_schedule(cfg)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             self.state = state if state is not None else dpmr.init_state(
                 cfg, mesh, hot_ids)
 
@@ -157,7 +154,7 @@ class DPMREngine:
         """Compiled StepFns for a given GLOBAL batch size (LRU-cached)."""
         fns = self._fns.pop(batch_size, None)
         if fns is None:
-            with compat.set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 fns = dpmr.make_step_fns(
                     self.cfg, self.mesh, batch_size,
                     kernel_impl=self.kernel_impl,
@@ -217,7 +214,7 @@ class DPMREngine:
     def train_step(self, batch: dict) -> dict:
         """One minibatch update; returns host-side metrics."""
         fns = self.step_fns(len(batch["labels"]))
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             self.state, m = fns.train_step(self.state,
                                            self.put_batch(batch))
         return {"loss": float(m["loss"]), "accuracy": float(m["accuracy"]),
@@ -282,7 +279,7 @@ class DPMREngine:
             acc_hot = jnp.zeros_like(self.state.hot)
             tot_loss = tot_acc = 0.0
             nb = 0
-            with compat.set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 for batch in batch_iter_fn():
                     fns = self.step_fns(len(batch["labels"]))
                     gc, gh, m = fns.grad_step(self.state,
@@ -318,7 +315,7 @@ class DPMREngine:
         `predict_padded`, which pads to a small ladder of bucketed sizes so
         the cache gets hits instead of recompiles."""
         fns = self.step_fns(len(batch["ids"]))
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             probs = fns.predict(self.state, self.put_batch(
                 {k: batch[k] for k in ("ids", "vals")}))
         # host_value, not np.asarray: under real multi-process execution
@@ -465,7 +462,7 @@ class DPMREngine:
         blind — the elastic-restart path (the strategy carry resets; the
         hot-set geometry, cfg.max_hot, must match)."""
         ck = self._checkpointer(directory, keep=3)
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             arrs, manifest = ck.restore_host(step)
             leaves, treedef = jax.tree.flatten(self.state)
             if len(arrs) != len(leaves):

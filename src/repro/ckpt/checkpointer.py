@@ -23,7 +23,8 @@ Guarantees:
     the step path (the leaves are host copies the moment save() returns, so
     later donation/mutation of the live buffers cannot leak into the file)
     and does serialization + fsync + the atomic renames on a daemon thread;
-    `wait()` joins before the next save or process exit.
+    `wait()` joins before the next save or process exit, and re-raises a
+    write that failed on that thread (so does the next `save`).
 
 Multi-process: under real `jax.distributed` execution every process calls
 `save` (the host gather of cross-process arrays is a collective —
@@ -52,6 +53,7 @@ class Checkpointer:
         self.keep = keep
         os.makedirs(directory, exist_ok=True)
         self._thread: threading.Thread | None = None
+        self._error: BaseException | None = None
 
     # -- save ---------------------------------------------------------------
 
@@ -102,14 +104,27 @@ class Checkpointer:
 
         if block:
             _write()
-        else:
-            self._thread = threading.Thread(target=_write, daemon=True)
-            self._thread.start()
+            return
+
+        def _write_async():
+            try:
+                _write()
+            except BaseException as e:  # noqa: BLE001 — re-raised by wait()
+                self._error = e
+
+        self._thread = threading.Thread(target=_write_async, daemon=True)
+        self._thread.start()
 
     def wait(self):
+        """Join the in-flight background write; a write that failed there
+        raises here (and so from the next `save`), never silently."""
         if self._thread is not None:
             self._thread.join()
             self._thread = None
+        err, self._error = self._error, None
+        if err is not None:
+            raise RuntimeError(
+                f"async checkpoint write to {self.dir} failed") from err
 
     def _gc(self):
         steps = self.all_steps()

@@ -21,7 +21,6 @@ from repro.core.dpmr import (
 )
 from repro.core.fsdp import dpmr_dense_linear, fsdp_specs
 from repro.core.hot_sharding import (
-    feature_counts,
     load_imbalance,
     select_hot,
     split_hot,
@@ -37,7 +36,7 @@ from repro.core.sparse import (
 
 __all__ = [
     "DPMRState", "Routing", "StepFns", "capacity", "combine_grads",
-    "dpmr_dense_linear", "feature_counts", "fsdp_specs",
+    "dpmr_dense_linear", "fsdp_specs",
     "hot_ids_from_corpus", "init_state", "load_imbalance", "make_schedule",
     "make_step_fns", "num_shards", "optimize", "owner_accumulate",
     "owner_apply", "padded_features", "route_build", "route_return",

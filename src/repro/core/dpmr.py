@@ -46,7 +46,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import DPMRConfig
 from repro.core import hot_sharding
 from repro.kernels import ops
@@ -147,9 +146,18 @@ def strategy_carry_len(cfg: DPMRConfig, mesh) -> int:
     shape-stable across strategies at negligible cost)."""
     from repro.api.strategies import get_strategy
 
-    carry = get_strategy(resolve_distribution(cfg, mesh)).init_carry(
-        make_strategy_context(cfg, mesh))
+    # shape only: a stateful carry is (F,)-sized, never build it here
+    strategy = get_strategy(resolve_distribution(cfg, mesh))
+    ctx = make_strategy_context(cfg, mesh)
+    carry = jax.eval_shape(lambda: strategy.init_carry(ctx))
     return 1 if carry is None else int(carry.shape[0])
+
+
+def _zeros(n: int, sharding) -> jax.Array:
+    """(n,) f32 zeros created in place under `sharding`: each device
+    writes only its own shard, nothing is built whole on one device."""
+    return jax.jit(functools.partial(jnp.zeros, (n,), jnp.float32),
+                   out_shardings=sharding)()
 
 
 def init_state(cfg: DPMRConfig, mesh, hot_ids=None) -> DPMRState:
@@ -157,18 +165,15 @@ def init_state(cfg: DPMRConfig, mesh, hot_ids=None) -> DPMRState:
     axes = _axes(mesh)
     shard = NamedSharding(mesh, P(axes))
     rep = NamedSharding(mesh, P())
-    cold = jax.device_put(jnp.zeros((f,), jnp.float32), shard)
-    cold_acc = jax.device_put(jnp.zeros((f,), jnp.float32), shard)
-    hot = jax.device_put(jnp.zeros((cfg.max_hot,), jnp.float32), rep)
-    hot_acc = jax.device_put(jnp.zeros((cfg.max_hot,), jnp.float32), rep)
     if hot_ids is None:
         hot_ids = jnp.full((cfg.max_hot,), hot_sharding.INT_MAX, jnp.int32)
-    hot_ids = jax.device_put(hot_ids.astype(jnp.int32), rep)
-    strat = jax.device_put(
-        jnp.zeros((num_shards(mesh) * strategy_carry_len(cfg, mesh),),
-                  jnp.float32), shard)
-    return DPMRState(cold, hot, hot_ids, cold_acc, hot_acc,
-                     jnp.zeros((), jnp.int32), strat)
+    hot_ids = jax.device_put(jnp.asarray(hot_ids).astype(jnp.int32), rep)
+    strat_len = num_shards(mesh) * strategy_carry_len(cfg, mesh)
+    return DPMRState(cold=_zeros(f, shard), hot=_zeros(cfg.max_hot, rep),
+                     hot_ids=hot_ids, cold_acc=_zeros(f, shard),
+                     hot_acc=_zeros(cfg.max_hot, rep),
+                     step=jnp.zeros((), jnp.int32),
+                     strat=_zeros(strat_len, shard))
 
 
 def optimize(cfg: DPMRConfig, theta, acc, grad, lr):
@@ -179,6 +184,26 @@ def optimize(cfg: DPMRConfig, theta, acc, grad, lr):
     """
     return optimizers.get_sparse_optimizer(cfg.optimizer).update(
         theta, acc, grad, lr, cfg)
+
+
+def predict_probs(vals, theta):
+    """Algorithm 9's head: sigmoid(sum_k vals * theta) per row.
+
+    The serving hot cache answers with this same function on mirrored
+    parameters, and its answers must equal the device predict bit for bit
+    on every backend. XLA picks a reduction order from the layout and may
+    contract a multiply into an add, both differently in different
+    programs; so the products are materialized behind a barrier and summed
+    in a fixed pairwise order of explicit adds, which XLA keeps."""
+    x = jax.lax.optimization_barrier(vals * theta)
+    k = x.shape[-1]
+    width = 1 << max(k - 1, 0).bit_length()
+    if width != k:
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - k)])
+    while x.shape[-1] > 1:
+        half = x.shape[-1] // 2
+        x = x[..., :half] + x[..., half:]
+    return jax.nn.sigmoid(jax.lax.optimization_barrier(x[..., 0]))
 
 
 def make_schedule(cfg: DPMRConfig) -> Callable:
@@ -356,12 +381,11 @@ def make_step_fns(cfg: DPMRConfig, mesh, batch_size: int,
     def predict_dev(cold_loc, hot, hot_ids, ids, vals):
         theta, _, _ = _device_fwd(cfg, strategy, ctx, kernel_impl,
                                   cold_loc, hot, hot_ids, ids, vals)
-        logits = jnp.sum(vals * theta, axis=-1)
-        return jax.nn.sigmoid(logits)
+        return predict_probs(vals, theta)
 
     shard = P(axes)
     rep = P()
-    smap = functools.partial(compat.shard_map, mesh=mesh, check_vma=False)
+    smap = functools.partial(jax.shard_map, mesh=mesh, check_vma=False)
 
     train_m = smap(train_dev,
                    in_specs=(shard, rep, rep, shard, rep, rep, shard,

@@ -12,35 +12,31 @@ statistic the paper passes to its sharding mappers.
 """
 from __future__ import annotations
 
-
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 INT_MAX = jnp.iinfo(jnp.int32).max
 
 
-def feature_counts(ids: jax.Array, num_features: int) -> jax.Array:
-    """Histogram of feature occurrences. ids: any shape, -1 = padding."""
-    flat = ids.reshape(-1)
-    return jnp.zeros((num_features,), jnp.int32).at[
-        jnp.where(flat >= 0, flat, num_features)
-    ].add(1, mode="drop")
-
-
-def select_hot(counts: jax.Array, threshold: float, max_hot: int
-               ) -> jax.Array:
+def select_hot(ids: np.ndarray, threshold: float, max_hot: int
+               ) -> np.ndarray:
     """Pick features with frequency above `threshold`, capped at max_hot.
 
-    Returns (max_hot,) int32 sorted ascending, padded with INT_MAX so
-    searchsorted stays valid.
+    ids: any shape, -1 = padding. Counted on the host over the ids present
+    (no (F,) histogram anywhere): the `max_hot` most frequent eligible
+    features, ties to the lower id. Returns (max_hot,) int32 sorted
+    ascending, padded with INT_MAX so searchsorted stays valid.
     """
-    total = jnp.maximum(jnp.sum(counts), 1)
-    freq = counts.astype(jnp.float32) / total.astype(jnp.float32)
-    eligible = freq >= threshold
-    score = jnp.where(eligible, counts, -1)
-    top_counts, top_ids = jax.lax.top_k(score, max_hot)
-    ids = jnp.where(top_counts > 0, top_ids, INT_MAX)
-    return jnp.sort(ids).astype(jnp.int32)
+    ids = np.asarray(ids).reshape(-1)
+    uniq, counts = np.unique(ids[ids >= 0], return_counts=True)
+    freq = counts.astype(np.float32) / np.float32(max(int(counts.sum()), 1))
+    keep = freq >= np.float32(threshold)
+    uniq, counts = uniq[keep], counts[keep]
+    top = uniq[np.lexsort((uniq, -counts))[:max_hot]]
+    out = np.full((max_hot,), int(INT_MAX), np.int32)
+    out[:len(top)] = np.sort(top)
+    return out
 
 
 def split_hot(ids_flat: jax.Array, hot_ids: jax.Array

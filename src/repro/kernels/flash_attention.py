@@ -75,7 +75,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     jax.jit,
     static_argnames=("causal", "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, interpret: bool = False):
     """q: (B, Sq, H, D); k, v: (B, Skv, KH, D) with H % KH == 0.
 
     Returns (B, Sq, H, D) in q.dtype. Forward only — the training path uses

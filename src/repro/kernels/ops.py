@@ -72,10 +72,10 @@ def segment_sum_sorted(ids, grads, *, impl: str = DEFAULT_IMPL,
 
 
 def select_pack(send, ids, carry_slots, *, k: int, impl: str = DEFAULT_IMPL):
-    """Fused top-k select+pack (see select_pack.py). Falls back to the XLA
-    chain when the capacity exceeds the kernel's VMEM-bounded maximum, so
-    the seam never changes semantics with geometry."""
-    if not is_pallas(impl) or ids.shape[1] > _sp.MAX_CAPACITY:
+    """Fused top-k select+pack (see select_pack.py). A Pallas impl above
+    the kernel's `MAX_CAPACITY` raises (the kernel refuses it) instead of
+    quietly running the XLA chain the caller did not ask for."""
+    if not is_pallas(impl):
         return _ref.select_pack_ref(send, ids, carry_slots, k=k)
     return _sp.select_pack(send, ids, carry_slots, k=k,
                            interpret=(impl == "pallas_interpret"))

@@ -174,8 +174,6 @@ def run_probe(arch: str, shape_name: str, overrides: str = "") -> dict:
 
     import jax
 
-    from repro import compat
-
     from repro.configs import SHAPES, TrainConfig
     from repro.configs.base import ParallelConfig
     from repro.launch.mesh import make_production_mesh
@@ -217,7 +215,7 @@ def run_probe(arch: str, shape_name: str, overrides: str = "") -> dict:
         if repl_vocab:
             rules["vocab"] = ()
     try:
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             for n in (1, 2):
                 cfg, units = _probe_config(spec0.cfg, n)
                 spec = dataclasses.replace(spec0, cfg=cfg)
@@ -287,8 +285,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
 
     import jax
 
-    from repro import compat
-
     from repro.configs import SHAPES, TrainConfig
     from repro.configs.base import ParallelConfig
     from repro.launch.mesh import make_production_mesh
@@ -310,7 +306,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
         parallel = dataclasses.replace(parallel, **_parse_overrides(overrides))
     tc = TrainConfig()
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             sdefs = trainer.state_defs(spec, cfg, tc, parallel)
             bdefs = registry.batch_defs(spec, shape)

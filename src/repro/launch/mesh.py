@@ -8,7 +8,13 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
+
+def make_mesh(axis_shapes, axis_names, devices=None):
+    """`jax.make_mesh` with every axis `Auto`: shardings propagate through
+    jit as GSPMD decides (jax's own default is `Explicit`)."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=auto, devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,7 +25,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -27,7 +33,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     if data * model > n:
         raise ValueError(f"need {data * model} devices, have {n}")
-    return compat.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 OUTER_AXES = ("pod",)   # mesh axes that cross DCN (inter-pod network)

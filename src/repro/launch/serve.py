@@ -36,10 +36,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.configs.base import ParallelConfig, TrainConfig
 from repro.launch.mesh import make_host_mesh
 from repro.models import registry
+from repro.runtime import compile_cache
 from repro.train import serve, trainer
 
 log = logging.getLogger("repro.serve")
@@ -140,7 +140,7 @@ def serve_dense(args) -> None:
     cfg = registry.smoke_config(args.arch) if args.smoke else \
         registry.get_spec(args.arch).cfg
     spec = registry.get_spec(args.arch)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = trainer.init_state(spec, cfg, TrainConfig(optimizer="sgd"),
                                    ParallelConfig(), jax.random.PRNGKey(0))
         params = state["params"]
@@ -221,6 +221,7 @@ def main():
     logging.basicConfig(level=logging.INFO)
     ap = build_parser()
     args = ap.parse_args()
+    compile_cache.enable()
     if args.sparse:
         if args.arch:
             # fail loudly instead of silently ignoring a dense config: the

@@ -29,12 +29,12 @@ import logging
 import jax
 import numpy as np
 
-from repro import compat
 from repro.ckpt.checkpointer import Checkpointer
 from repro.configs.base import ParallelConfig, TrainConfig
 from repro.data import Cursor, ShardedLoader, get_source
 from repro.launch.mesh import make_host_mesh
 from repro.models import registry
+from repro.runtime import compile_cache
 from repro.runtime.fault_tolerance import PreemptionGuard, StragglerWatchdog
 from repro.train import trainer
 
@@ -187,7 +187,7 @@ def train_loop(args, fail_injector=None) -> dict:
     guard = PreemptionGuard() if args.preemption_guard else None
     watchdog = StragglerWatchdog()
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = trainer.init_state(spec, cfg, tc, pc,
                                    jax.random.PRNGKey(tc.seed))
         start_step = 0
@@ -313,6 +313,7 @@ def build_parser():
 def main():
     logging.basicConfig(level=logging.INFO)
     args = build_parser().parse_args()
+    compile_cache.enable()
     if args.num_processes > 1 or args.local_devices:
         # must run before the first jax computation (backend init reads
         # XLA_FLAGS once; jax.distributed must precede any collective)

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.sharding import Annotated
 
@@ -346,7 +345,7 @@ def context_parallel_attention(q, k, v, *, causal: bool = True,
     """
     from jax.sharding import PartitionSpec as P
 
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     b, s, h, hd = q.shape
     if (mesh is None or mesh.empty or axis not in mesh.axis_names
             or mesh.shape[axis] == 1 or s % mesh.shape[axis] != 0):
@@ -447,7 +446,7 @@ def _constrain_scores(scores):
     try:
         from jax.sharding import PartitionSpec as P
 
-        mesh = compat.get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         if (mesh is None or mesh.empty or "model" not in mesh.axis_names
                 or scores.shape[-1] % mesh.shape["model"] != 0):
             return scores

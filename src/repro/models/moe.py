@@ -23,7 +23,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.sharding import Annotated
 
@@ -40,7 +39,7 @@ def _constrain_ep(x, e: int, spec_dims):
     from jax.sharding import PartitionSpec as P
 
     try:
-        mesh = compat.get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         if mesh is None or mesh.empty:
             return x
         dims = []

@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig, ParallelConfig
 from repro.models import common, layers
 from repro.sharding import Annotated
@@ -54,7 +53,7 @@ def _constrain(x, spec_tail):
     try:
         import jax.interpreters.pxla  # noqa: F401
 
-        mesh = compat.get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         if mesh is None or mesh.empty:
             return x
         dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)

@@ -29,8 +29,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 BLOCK = 2048
 
 
@@ -102,7 +100,7 @@ def compress_psum(g: jax.Array, err: jax.Array, axis: str
     q_all = jax.lax.all_gather(q, axis)               # (pods, blocks, BLOCK)
     s_all = jax.lax.all_gather(scale, axis)           # (pods, blocks, 1)
     deq = (q_all.astype(jnp.float32) * s_all).sum(0).reshape(-1)[:n]
-    npods = compat.axis_size(axis)
+    npods = jax.lax.axis_size(axis)
     return deq.reshape(shape) / npods, new_err
 
 

@@ -83,6 +83,24 @@ def _force_local_device_count(n: int) -> None:
             "initialized and XLA_FLAGS can no longer take effect")
 
 
+def _check_local_device_count(n: int) -> None:
+    """The forced count only exists on the CPU backend: on an accelerator
+    the flag does nothing, so a request it cannot honour raises instead of
+    running on whatever devices the process happens to see."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"local_device_count={n} emulates CPU devices, but this "
+            f"process's backend is {backend!r}; on an accelerator every "
+            "process uses the devices it sees — drop the option")
+    if jax.local_device_count() != n:
+        raise RuntimeError(
+            f"local_device_count={n} requested, but the CPU backend has "
+            f"{jax.local_device_count()} local devices")
+
+
 def initialize(coordinator: str = "", num_processes: int = 1,
                process_id: int = 0,
                local_device_count: int | None = None) -> ProcessContext:
@@ -92,6 +110,8 @@ def initialize(coordinator: str = "", num_processes: int = 1,
     device count and does NOT touch the collectives config (see module
     docstring, step 2). Multi-process: configures gloo and joins the
     coordinator at `coordinator` ("host:port"; process 0 serves it).
+    `local_device_count` is a CPU emulation knob: it raises when the
+    initialized backend is not the CPU or did not take the count.
     Idempotent per process; returns the `ProcessContext` that `context()`
     will keep handing out.
     """
@@ -113,6 +133,8 @@ def initialize(coordinator: str = "", num_processes: int = 1,
         jax.distributed.initialize(coordinator_address=coordinator,
                                    num_processes=num_processes,
                                    process_id=process_id)
+    if local_device_count is not None:
+        _check_local_device_count(local_device_count)
     _CONTEXT = ProcessContext(num_processes=int(num_processes),
                               process_id=int(process_id),
                               local_device_count=jax.local_device_count(),

@@ -10,8 +10,8 @@ path.
 
 This module is the serving consumer of `repro.core.hot_sharding`:
 
-  feature_counts   histogram over a sliding window of recent request ids
-  select_hot       picks the head set (frequency >= `threshold`, capped at
+  select_hot       picks the head set from a sliding window of recent
+                   request ids (frequency >= `threshold`, capped at
                    `max_hot`) exactly like the trainer's initParameters-time
                    statistic
   split_hot        classifies the selected ids against the MODEL's
@@ -52,7 +52,7 @@ class HotCacheConfig:
 
     max_hot:        mirror slots (select_hot cap) — the head-set size
     threshold:      minimum in-window frequency for a feature to be cached
-    window:         sliding request window feeding feature_counts
+    window:         sliding request window feeding select_hot
     refresh_every:  staleness bound, in lookups: a mirror older than this
                     many served requests is refreshed before the next hit
     """
@@ -72,12 +72,9 @@ class HotCacheConfig:
                 f"refresh_every must be >= 1: {self.refresh_every}")
 
 
-@jax.jit
-def _hit_predict(theta: jax.Array, vals: jax.Array) -> jax.Array:
-    """The device predict stage's math on mirrored parameters: identical
-    ops/dtypes (f32 row-sum then sigmoid), so a fresh hit is bit-identical
-    to the sparse path."""
-    return jax.nn.sigmoid(jnp.sum(vals * theta, axis=-1))
+# the device predict stage's own head (core.dpmr.predict_probs) on
+# mirrored parameters, so a fresh hit is bit-identical to the sparse path
+_hit_predict = jax.jit(dpmr.predict_probs)
 
 
 class HotFeatureCache:
@@ -137,16 +134,14 @@ class HotFeatureCache:
 
     def _refresh_locked(self) -> None:
         state = self.engine.state
-        f = dpmr.padded_features(self.engine.cfg, self.engine.mesh)
         if self._window:
             flat = np.concatenate(list(self._window))
         else:
             flat = np.empty((0,), np.int32)
-        counts = hot_sharding.feature_counts(jnp.asarray(flat, jnp.int32), f)
-        sel = hot_sharding.select_hot(counts, self.config.threshold,
+        sel = hot_sharding.select_hot(flat, self.config.threshold,
                                       self.config.max_hot)
         valid = sel != hot_sharding.INT_MAX
-        safe = jnp.where(valid, sel, 0)
+        safe = jnp.asarray(np.where(valid, sel, 0))
         # model-hot features live in the replicated `hot` table, everything
         # else in the owner-sharded `cold` table — exactly the split the
         # device forward makes, so mirrored values are the exact f32
@@ -155,7 +150,7 @@ class HotFeatureCache:
         vals = jnp.where(is_hot, state.hot[jnp.clip(hot_slot, 0)],
                          state.cold[safe])
         vals = jnp.where(valid, vals, 0.0)
-        self._ids = np.asarray(jax.device_get(sel))
+        self._ids = sel
         self._vals = np.asarray(jax.device_get(vals), np.float32)
         self._mirror_step = int(state.step)
         self._lookups_since_refresh = 0
@@ -191,6 +186,6 @@ class HotFeatureCache:
             return None
         theta = np.where(found, table_vals[pos], np.float32(0.0)) \
             .astype(np.float32).reshape(ids.shape)
-        probs = np.asarray(_hit_predict(theta, vals))
+        probs = np.asarray(_hit_predict(vals, theta))
         self.metrics.count("cache_hits")
         return probs

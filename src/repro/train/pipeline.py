@@ -23,13 +23,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
+from repro.launch.mesh import make_mesh
 
 
 def make_pp_mesh(pipe: int, data: int = 1):
     if data == 1:
-        return compat.make_mesh((pipe,), ("pipe",))
-    return compat.make_mesh((pipe, data), ("pipe", "data"))
+        return make_mesh((pipe,), ("pipe",))
+    return make_mesh((pipe, data), ("pipe", "data"))
 
 
 def pipeline_apply(stage_params, micro_in, stage_fn: Callable, mesh,
@@ -84,7 +84,7 @@ def pipeline_apply(stage_params, micro_in, stage_fn: Callable, mesh,
             jnp.where(stage == last, outs, jnp.zeros_like(outs)), axis)
         return outs
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), stage_params), P()),
         out_specs=P(),

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro import sharding as shd
 from repro.configs.base import ModelConfig, ParallelConfig, TrainConfig
 from repro.models import common
@@ -136,7 +135,7 @@ def make_train_step(spec, cfg: ModelConfig, train_cfg: TrainConfig,
         def train_step(state, batch):
             pspec = jax.tree.map(lambda _: P(), state["params"])
             bspec = jax.tree.map(lambda _: P("pod"), batch)
-            body = compat.shard_map(
+            body = jax.shard_map(
                 pod_body, mesh=mesh,
                 in_specs=(pspec, pspec, bspec),
                 out_specs=(pspec, pspec, P(), jax.tree.map(lambda _: P(),

@@ -42,6 +42,33 @@ def test_async_save(tmp_path):
     assert ck.latest_step() == 5
 
 
+@pytest.mark.parametrize("surface", ["wait", "next_save"])
+def test_async_save_failure_raises(tmp_path, monkeypatch, surface):
+    """A background write that fails is re-raised from `wait()` and from
+    the next `save()`, never lost on its thread."""
+    from repro.ckpt import checkpointer
+
+    ck = Checkpointer(str(tmp_path), keep=3)
+
+    def broken_save(*a, **k):
+        raise OSError("disk full")
+
+    monkeypatch.setattr(checkpointer.np, "save", broken_save)
+    ck.save(5, _state(5.0), block=False)
+    with pytest.raises(RuntimeError, match="async checkpoint") as info:
+        if surface == "wait":
+            ck.wait()
+        else:
+            ck.save(6, _state(6.0), block=False)
+    assert isinstance(info.value.__cause__, OSError)
+    assert ck.latest_step() is None
+    monkeypatch.undo()
+    ck.wait()                       # the error was reported once
+    ck.save(7, _state(7.0), block=False)
+    ck.wait()
+    assert ck.latest_step() == 7
+
+
 def test_atomic_no_partial_dirs(tmp_path):
     ck = Checkpointer(str(tmp_path), keep=3)
     ck.save(1, _state())

@@ -12,7 +12,7 @@ from repro.api import (DistributionStrategy, DPMREngine, WireBytes,
                        register_strategy)
 from repro.api.strategies import StrategyContext
 from repro.configs.base import DPMRConfig
-from repro.core import dpmr, hot_sharding, sparse
+from repro.core import dpmr, hot_sharding, reference, sparse
 from repro.data import get_source, sparse_corpus
 from repro.launch.mesh import make_host_mesh, tier_axes, tier_shards
 
@@ -38,27 +38,6 @@ def _cfg(**kw):
                 learning_rate=1.0, max_hot=32)
     base.update(kw)
     return DPMRConfig(**base)
-
-
-def _dense_lr_oracle(batches, f, lr, iters, grad_scale="mean"):
-    """Numpy full-batch GD logistic regression (the ground truth)."""
-    theta = np.zeros(f, np.float32)
-    for _ in range(iters):
-        acc = np.zeros(f, np.float64)
-        nb = 0
-        for b in batches:
-            ids, vals, y = b["ids"], b["vals"], b["labels"]
-            th = theta[np.clip(ids, 0, None)] * (ids >= 0)
-            logits = (th * vals).sum(1)
-            p = 1 / (1 + np.exp(-logits))
-            g = vals * (p - y)[:, None]
-            if grad_scale == "mean":
-                g = g / ids.shape[0]
-            np.add.at(acc, np.clip(ids, 0, f - 1),
-                      np.where(ids >= 0, g, 0.0))
-            nb += 1
-        theta = theta - lr * (acc / nb).astype(np.float32)
-    return theta
 
 
 # ---------------------------------------------------------------------------
@@ -109,9 +88,9 @@ def test_overflow_counted_when_capacity_too_small():
 
 
 def test_hot_split():
-    counts = jnp.asarray([100, 1, 50, 1, 1, 80, 1, 1], jnp.int32)
-    hot = hot_sharding.select_hot(counts, threshold=0.1, max_hot=4)
-    hot_np = np.asarray(hot)
+    occurrences = np.repeat(np.arange(8), [100, 1, 50, 1, 1, 80, 1, 1])
+    hot_np = hot_sharding.select_hot(occurrences, threshold=0.1, max_hot=4)
+    hot = jnp.asarray(hot_np)
     assert set(hot_np[hot_np < 2**31 - 1]) == {0, 2, 5}
     ids = jnp.asarray([0, 1, 5, -1, 3], jnp.int32)
     slot, is_hot, cold = hot_sharding.split_hot(ids, hot)
@@ -215,14 +194,39 @@ def test_dpmr_matches_dense_oracle(distribution):
     eng = DPMREngine(cfg, mesh, hot_ids=hot)
     eng.fit(lambda: iter(batches))
     f = dpmr.padded_features(cfg, mesh)
-    oracle = _dense_lr_oracle(batches, f, cfg.learning_rate, cfg.iterations)
-    # reassemble full theta: cold + hot written back at hot_ids
-    theta = np.asarray(eng.state.cold).copy()
-    hids = np.asarray(eng.state.hot_ids)
-    hvals = np.asarray(eng.state.hot)
-    real = hids < 2**31 - 1
-    theta[hids[real]] = hvals[real]
-    np.testing.assert_allclose(theta, oracle, atol=2e-4)
+    oracle = reference.gd_iterations(cfg, batches, cfg.iterations, f)
+    # full theta: cold + hot written back at hot_ids
+    theta = reference.engine_table(eng.state)
+    np.testing.assert_allclose(theta, np.asarray(oracle), atol=2e-4)
+
+
+@pytest.mark.parametrize("optimizer", ["sgd", "adagrad"])
+def test_fit_sgd_matches_reference(optimizer):
+    """Minibatch SGD through the engine == `core.reference.sgd_steps`, the
+    routing-free float32 reference the chip smoke compares against."""
+    mesh = make_host_mesh(1, 1)
+    cfg = _cfg(optimizer=optimizer, max_hot=16)
+    batches = list(_batches(128, 6))
+    hot = hot_ids_from_corpus(cfg, batches[:2], mesh)
+    eng = DPMREngine(cfg, mesh, hot_ids=hot)
+    hist = eng.fit_sgd(batches)
+    assert all(h["overflow"] == 0 for h in hist)
+    theta, _, losses = reference.sgd_steps(
+        cfg, batches, dpmr.padded_features(cfg, mesh))
+    np.testing.assert_allclose([h["loss"] for h in hist], losses, rtol=1e-5)
+    np.testing.assert_allclose(reference.engine_table(eng.state),
+                               np.asarray(theta), rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("kw", [{"optimizer": "momentum"},
+                                {"schedule": "warmup_cosine"}])
+def test_reference_refuses_what_it_lacks(kw):
+    """The reference writes out sgd and adagrad at a constant rate; any
+    other optimizer or schedule is an error, never a silent mismatch."""
+    with pytest.raises(ValueError, match="the reference has"):
+        reference.sgd_steps(_cfg(**kw), [])
+    with pytest.raises(ValueError, match="the reference has"):
+        reference.gd_iterations(_cfg(**kw), [], 1)
 
 
 def test_strategies_agree():

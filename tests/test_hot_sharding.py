@@ -1,14 +1,17 @@
 """`core/hot_sharding.py` unit tests + serving hot-cache correctness.
 
-The hot-sharding primitives (feature_counts / select_hot / split_hot /
+The hot-sharding primitives (select_hot / split_hot /
 load_imbalance) were consumer-less until the serving subsystem; this file
 pins their semantics directly, then asserts the serving-facing contract of
 `repro.serve.hot_cache`: a cached hit is BIT-IDENTICAL to the uncached
 sparse predict while the mirror is fresh, and the staleness bound forces a
 refresh (never serving stale parameter values after training moved on).
 """
+import collections
+
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.api import DPMREngine, hot_ids_from_corpus
 from repro.configs.base import DPMRConfig
@@ -26,48 +29,70 @@ F = 1 << 10
 # ---------------------------------------------------------------------------
 
 
-def test_feature_counts_histogram():
-    ids = jnp.asarray([[0, 1, 1], [2, -1, 1]], jnp.int32)
-    counts = np.asarray(hot_sharding.feature_counts(ids, 4))
-    assert counts.tolist() == [1, 3, 1, 0]
+def test_select_hot_counts_occurrences():
+    ids = np.asarray([[0, 1, 1], [2, -1, 1]], np.int32)
+    # 1 occurs three times: the single slot goes to it
+    assert hot_sharding.select_hot(ids, 0.0, 1).tolist() == [1]
+    assert hot_sharding.select_hot(ids, 0.0, 4).tolist() == [0, 1, 2,
+                                                             INT_MAX]
 
 
-def test_feature_counts_drops_padding_only():
-    ids = jnp.asarray([-1, -1, 3], jnp.int32)
-    counts = np.asarray(hot_sharding.feature_counts(ids, 4))
-    assert counts.sum() == 1 and counts[3] == 1
+def test_select_hot_drops_padding_only():
+    ids = np.asarray([-1, -1, 3], np.int32)
+    # padding neither counts as a feature nor dilutes the frequencies
+    assert hot_sharding.select_hot(ids, 1.0, 2).tolist() == [3, INT_MAX]
 
 
-def test_feature_counts_any_shape():
-    flat = jnp.arange(6, dtype=jnp.int32)
-    assert np.array_equal(
-        np.asarray(hot_sharding.feature_counts(flat, 8)),
-        np.asarray(hot_sharding.feature_counts(flat.reshape(2, 3), 8)))
+def test_select_hot_any_shape():
+    flat = np.asarray([0, 0, 1, 2, 2, 2], np.int32)
+    assert np.array_equal(hot_sharding.select_hot(flat, 0.0, 2),
+                          hot_sharding.select_hot(flat.reshape(2, 3), 0.0, 2))
+
+
+def _with_counts(counts):
+    """Flat ids in which feature i occurs counts[i] times."""
+    return np.repeat(np.arange(len(counts), dtype=np.int32), counts)
 
 
 def test_select_hot_threshold_and_sorting():
-    counts = jnp.asarray([10, 0, 5, 1], jnp.int32)    # total 16
-    ids = np.asarray(hot_sharding.select_hot(counts, 0.3, 3))
+    ids = _with_counts([10, 0, 5, 1])                  # total 16
+    got = hot_sharding.select_hot(ids, 0.3, 3)
     # freq >= 0.3 keeps features 0 (0.625) and 2 (0.3125) only
-    assert ids.tolist() == [0, 2, INT_MAX]
+    assert got.tolist() == [0, 2, INT_MAX]
 
 
 def test_select_hot_max_hot_cap():
-    counts = jnp.asarray([4, 3, 2, 1], jnp.int32)
-    ids = np.asarray(hot_sharding.select_hot(counts, 0.0, 2))
-    assert ids.tolist() == [0, 1]        # two largest counts, sorted
+    got = hot_sharding.select_hot(_with_counts([4, 3, 2, 1]), 0.0, 2)
+    assert got.tolist() == [0, 1]        # two largest counts, sorted
 
 
 def test_select_hot_zero_count_never_selected():
-    counts = jnp.zeros((4,), jnp.int32).at[1].set(2)
-    ids = np.asarray(hot_sharding.select_hot(counts, 0.0, 4))
-    assert ids.tolist() == [1, INT_MAX, INT_MAX, INT_MAX]
+    got = hot_sharding.select_hot(_with_counts([0, 2, 0, 0]), 0.0, 4)
+    assert got.tolist() == [1, INT_MAX, INT_MAX, INT_MAX]
 
 
 def test_select_hot_nothing_eligible():
-    counts = jnp.asarray([1, 1], jnp.int32)
-    ids = np.asarray(hot_sharding.select_hot(counts, 0.9, 2))
-    assert ids.tolist() == [INT_MAX, INT_MAX]
+    got = hot_sharding.select_hot(_with_counts([1, 1]), 0.9, 2)
+    assert got.tolist() == [INT_MAX, INT_MAX]
+
+
+@pytest.mark.parametrize("threshold,max_hot", [(0.0, 8), (0.01, 64),
+                                                (0.05, 4), (1.0, 8)])
+def test_select_hot_matches_brute_force(threshold, max_hot):
+    """The selection == a plain count over every feature: frequency >=
+    threshold, the max_hot most frequent, ties to the lower id."""
+    rng = np.random.default_rng(int(threshold * 100) + max_hot)
+    ids = np.minimum(rng.zipf(1.3, size=(64, 16)), F) - 1
+    ids = np.where(rng.random(ids.shape) < 0.1, -1, ids).astype(np.int32)
+    counts = collections.Counter(ids[ids >= 0].tolist())
+    total = sum(counts.values())
+    eligible = sorted((-c, i) for i, c in counts.items()
+                      if np.float32(c) / np.float32(total)
+                      >= np.float32(threshold))
+    want = sorted(i for _, i in eligible[:max_hot])
+    want += [INT_MAX] * (max_hot - len(want))
+    got = hot_sharding.select_hot(ids, threshold, max_hot)
+    assert got.tolist() == want
 
 
 def test_split_hot_partition():

@@ -1,4 +1,5 @@
 """Per-kernel interpret-mode validation: shape/dtype sweeps vs ref.py."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -162,17 +163,23 @@ def test_select_pack_duplicate_keys_tiebreak():
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
-def test_select_pack_capacity_fallback():
-    """Above MAX_CAPACITY the dispatcher silently runs the XLA chain (the
-    seam never errors with geometry); the raw kernel refuses."""
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+def test_select_pack_capacity_fallback(impl):
+    """Above MAX_CAPACITY the XLA chain still answers, while a Pallas impl
+    raises like the raw kernel: the dispatcher never swaps in a path the
+    caller did not ask for."""
     from repro.kernels import select_pack as sp
 
     p, cap, k = 2, sp.MAX_CAPACITY + 8, 4
     send, ids, carry = _select_pack_case(p, cap, seed=3)
-    want = ref.select_pack_ref(send, ids, carry, k=k)
-    got = ops.select_pack(send, ids, carry, k=k, impl="pallas_interpret")
-    for g, w in zip(got, want):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    if impl == "xla":
+        want = ref.select_pack_ref(send, ids, carry, k=k)
+        got = ops.select_pack(send, ids, carry, k=k, impl=impl)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    else:
+        with pytest.raises(ValueError, match="MAX_CAPACITY"):
+            ops.select_pack(send, ids, carry, k=k, impl=impl)
     with pytest.raises(ValueError, match="MAX_CAPACITY"):
         sp.select_pack(send, ids, carry, k=k, interpret=True)
 
@@ -290,7 +297,6 @@ def test_step_fns_parity_single_device():
     """topk_reduce train steps on a 1-device mesh: kernel_impl
     "pallas_interpret" (select_pack + owner_accumulate kernels live) is
     bit-identical to "xla" — params AND the error-feedback carry."""
-    from repro import compat
     from repro.configs.base import DPMRConfig
     from repro.core import dpmr
     from repro.launch.mesh import make_host_mesh
@@ -308,7 +314,7 @@ def test_step_fns_parity_single_device():
                  rng.integers(0, 2, size=(b,)).astype(np.int32))}
     outs = {}
     for impl in ("xla", "pallas_interpret"):
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             fns = dpmr.make_step_fns(cfg, mesh, b, kernel_impl=impl)
             st = dpmr.init_state(cfg, mesh)
             for _ in range(3):
@@ -333,8 +339,8 @@ def test_step_fns_parity_multidevice():
     body = """
 import json
 import numpy as np
+import jax
 import jax.numpy as jnp
-from repro import compat
 from repro.configs.base import DPMRConfig
 from repro.core import dpmr
 from repro.launch.mesh import make_host_mesh
@@ -354,7 +360,7 @@ for dist in ("a2a", "topk_reduce"):
                  rng.integers(0, 2, size=(b,)).astype(np.int32))}
     res = {}
     for impl in ("xla", "pallas_interpret"):
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             fns = dpmr.make_step_fns(cfg, mesh, b, kernel_impl=impl)
             st = dpmr.init_state(cfg, mesh)
             for _ in range(3):

@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.configs import ARCH_IDS
 from repro.configs.base import ParallelConfig, TrainConfig
 from repro.launch.mesh import make_host_mesh
@@ -53,7 +52,7 @@ def test_train_step_no_nans(arch):
     mesh = make_host_mesh(1, 1)
     tc = TrainConfig(learning_rate=1e-2, warmup_steps=2, total_steps=10)
     pc = ParallelConfig(microbatches=1)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = trainer.init_state(spec, cfg, tc, pc, jax.random.PRNGKey(0))
         step = jax.jit(trainer.make_train_step(spec, cfg, tc, pc, mesh))
         b = _batch(cfg, b=4, s=16)

@@ -31,8 +31,7 @@ def run_py(body: str, devices: int = 8, timeout: int = 600) -> dict:
 COMMON = """
 import json
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_host_mesh, make_mesh
 """
 
 
@@ -51,7 +50,7 @@ losses = {}
 for (d, m) in [(1,1),(4,2),(2,4)]:
     mesh = make_host_mesh(d, m)
     pc = ParallelConfig(microbatches=2)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = trainer.init_state(spec, cfg, tc, pc, jax.random.PRNGKey(0))
         step = jax.jit(trainer.make_train_step(spec, cfg, tc, pc, mesh))
         ds = LMDataset(LMDataConfig(cfg.vocab_size, 16, 8))
@@ -102,7 +101,7 @@ src = get_source("zipf_sparse", batch_size=256, num_features=1<<12,
 batches = list(src.iter_batches(limit=3))
 base = dict(num_features=1<<12, max_features_per_sample=16, iterations=2,
             learning_rate=1.0, max_hot=32)
-mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 colds = {}
 for dist in ("a2a", "hier_a2a"):
     eng = DPMREngine(DPMRConfig(distribution=dist, **base), mesh)
@@ -147,7 +146,7 @@ src = get_source("zipf_sparse", batch_size=256, num_features=1<<12,
 batches = list(src.iter_batches(limit=3))
 base = dict(num_features=1<<12, max_features_per_sample=16, iterations=2,
             learning_rate=1.0, max_hot=32)
-mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 out = {}
 state = {}
 for dist in ("a2a", "overlap_a2a"):
@@ -188,7 +187,7 @@ w = jnp.asarray(rng.normal(size=(D, F)), jnp.float32)
 x = jnp.asarray(rng.normal(size=(B, D)), jnp.float32)
 
 def staged(w, x):
-    f = compat.shard_map(lambda ws, xs: dpmr_dense_linear(ws, xs, "data"),
+    f = jax.shard_map(lambda ws, xs: dpmr_dense_linear(ws, xs, "data"),
                          mesh=mesh, in_specs=(P("data", None), P()),
                          out_specs=P(), check_vma=False)
     return f(w, x)
@@ -196,7 +195,7 @@ def staged(w, x):
 def loss_staged(w, x): return jnp.sum(jnp.sin(staged(w, x)))
 def loss_plain(w, x): return jnp.sum(jnp.sin(x @ w))
 
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     y1 = staged(w, x)
     g1 = jax.grad(loss_staged)(w, x)
 y2 = x @ w
@@ -208,13 +207,6 @@ print(json.dumps({
     assert out["fwd"] < 1e-4 and out["bwd"] < 1e-4, out
 
 
-@pytest.mark.xfail(
-    tuple(int(x) for x in __import__("jax").__version__.split(".")[:2])
-    < (0, 5),
-    reason="old-jax partial-auto shard_map rejects sharding constraints "
-           "naming the manual 'pod' axis (transformer._constrain inside "
-           "the pod-manual region); fixed in newer jax",
-    strict=False)
 def test_cross_pod_compressed_training_converges():
     """Compressed cross-pod grads: loss tracks uncompressed within 5%."""
     out = run_py(COMMON + """
@@ -228,9 +220,9 @@ spec = registry.get_spec("yi-6b")
 tc = TrainConfig(learning_rate=1e-2, warmup_steps=0, total_steps=20)
 
 def run(compress):
-    mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     pc = ParallelConfig(compress_pod_grads=compress)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = trainer.init_state(spec, cfg, tc, pc, jax.random.PRNGKey(0))
         step = jax.jit(trainer.make_train_step(spec, cfg, tc, pc, mesh))
         ds = LMDataset(LMDataConfig(cfg.vocab_size, 16, 8))
@@ -255,7 +247,7 @@ q = jnp.asarray(rng.normal(size=(b,s,h,d)), jnp.float32)
 k = jnp.asarray(rng.normal(size=(b,s,kh,d)), jnp.float32)
 v = jnp.asarray(rng.normal(size=(b,s,kh,d)), jnp.float32)
 res = {}
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     for causal, window in [(True,0),(True,16),(False,0)]:
         cp = jax.jit(lambda q,k,v: layers.context_parallel_attention(
             q,k,v,causal=causal,window=window,kv_block=16))(q,k,v)
@@ -287,7 +279,7 @@ res = {}
 for mode in ("auto", "cp"):
     mesh = make_host_mesh(2, 4)
     pc = ParallelConfig(attn_mode=mode)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = trainer.init_state(spec, cfg, tc, pc, jax.random.PRNGKey(0))
         step = jax.jit(trainer.make_train_step(spec, cfg, tc, pc, mesh))
         ds = LMDataset(LMDataConfig(cfg.vocab_size, 16, 8))
@@ -307,14 +299,14 @@ from repro.train import trainer
 from repro.configs.base import TrainConfig, ParallelConfig
 from repro.data.pipeline import LMDataset, LMDataConfig, encdec_batch
 
-mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 res = {}
 for arch in ["granite-8b", "mixtral-8x22b", "zamba2-2.7b", "whisper-small"]:
     cfg = registry.smoke_config(arch)
     spec = registry.get_spec(arch)
     tc = TrainConfig(learning_rate=1e-2, warmup_steps=0, total_steps=5)
     pc = ParallelConfig()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = trainer.init_state(spec, cfg, tc, pc, jax.random.PRNGKey(0))
         step = jax.jit(trainer.make_train_step(spec, cfg, tc, pc, mesh))
         ds = LMDataset(LMDataConfig(cfg.vocab_size, 16, 8))

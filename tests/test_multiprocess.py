@@ -25,6 +25,7 @@ def _env():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     env.pop("XLA_FLAGS", None)      # --local-devices owns the device count
+    env["JAX_PLATFORMS"] = "cpu"    # a CPU emulation of multi-host
     return env
 
 
@@ -70,9 +71,11 @@ def test_async_ckpt_survives_kill_and_elastic_restart(tmp_path):
     ckpt = str(tmp_path / "ck")
     mp = ["--coordinator", "127.0.0.1:12749", "--num-processes", "2",
           "--local-devices", "2"]
-    p1 = _train([*mp, "--process-id", "1"], steps=40, save_every=2,
+    # far more steps than run before the kill: a warm compile cache makes
+    # steps fast, and the kill must land mid-run whatever the speed
+    p1 = _train([*mp, "--process-id", "1"], steps=100000, save_every=2,
                 ckpt=ckpt, async_ckpt=True)
-    p0 = _train([*mp, "--process-id", "0"], steps=40, save_every=2,
+    p0 = _train([*mp, "--process-id", "0"], steps=100000, save_every=2,
                 ckpt=ckpt, async_ckpt=True)
     try:
         # wait for at least one COMPLETE checkpoint (manifest present)
@@ -103,10 +106,14 @@ def test_async_ckpt_survives_kill_and_elastic_restart(tmp_path):
     # relaunch at H=1 (4 local devices, same 4-device global mesh): the
     # cursor was recorded under num_hosts=2, so restore reassigns
     # ownership (reshard_data_state semantics) and training continues
-    resumed = _summary(_train(["--local-devices", "4"], steps=8,
+    # from the newest complete checkpoint for 4 more steps
+    saved = max(int(d[5:]) for d in os.listdir(ckpt)
+                if d.startswith("step_") and not d.endswith(".tmp")
+                and os.path.exists(os.path.join(ckpt, d, "manifest.json")))
+    resumed = _summary(_train(["--local-devices", "4"], steps=saved + 4,
                               save_every=4, ckpt=ckpt))
-    assert resumed["last_step"] == 8
-    assert 1 <= len(resumed["losses"]) <= 7      # resumed, not restarted
+    assert resumed["last_step"] == saved + 4
+    assert len(resumed["losses"]) == 4           # resumed, not restarted
     assert resumed["hosts"] == 1 and resumed["num_processes"] == 1
 
 
@@ -129,3 +136,21 @@ def test_all_hosts_emulation_equals_stride_union():
             for k in got}
     for k in want:
         np.testing.assert_array_equal(np.asarray(got[k]), want[k])
+
+
+@pytest.mark.parametrize("extra", [0, 1])
+def test_local_device_count_must_take_effect(extra):
+    """`local_device_count` is honoured exactly or raises: a count the
+    backend did not take (here, one more than the CPU backend has) is an
+    error, not a silent run on other devices."""
+    import jax
+
+    sys.path.insert(0, os.path.join(REPO, "src"))
+    from repro.runtime import multiprocess
+
+    n = jax.local_device_count() + extra
+    if extra == 0:
+        multiprocess._check_local_device_count(n)
+    else:
+        with pytest.raises(RuntimeError, match="local_device_count"):
+            multiprocess._check_local_device_count(n)

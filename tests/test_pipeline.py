@@ -24,7 +24,6 @@ def test_pipeline_matches_sequential():
     out = run_py("""
 import json
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
 from repro.train.pipeline import make_pp_mesh, pipeline_apply
 
 S, M, B, D = 4, 8, 2, 16
@@ -36,7 +35,7 @@ x = jnp.asarray(rng.normal(size=(M, B, D)), jnp.float32)
 def stage_fn(w_s, h):
     return jnp.tanh(h @ w_s)
 
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     y_pipe = pipeline_apply({"w": w}, x,
                             lambda p, h: stage_fn(p["w"], h), mesh)
 
@@ -57,7 +56,7 @@ def loss_ref(w):
         y = jnp.tanh(y @ w[s])
     return jnp.sum(jnp.sin(y))
 
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     g_pipe = jax.grad(loss)(w)
 g_ref = jax.grad(loss_ref)(w)
 gdiff = float(jnp.max(jnp.abs(g_pipe - g_ref)))

@@ -88,8 +88,7 @@ def test_hot_split_partition(seed, max_hot):
     """hot + cold is a partition: every valid id goes to exactly one side."""
     rng = np.random.default_rng(seed)
     ids = rng.integers(-1, 1000, size=64).astype(np.int32)
-    counts = hot_sharding.feature_counts(jnp.asarray(ids), 1000)
-    hot = hot_sharding.select_hot(counts, 0.01, max_hot)
+    hot = jnp.asarray(hot_sharding.select_hot(ids, 0.01, max_hot))
     slot, is_hot, cold = hot_sharding.split_hot(jnp.asarray(ids), hot)
     is_hot = np.asarray(is_hot)
     cold = np.asarray(cold)

@@ -46,7 +46,7 @@ from repro.analysis.wire import wire_total
 from repro.api import (DPMREngine, get_strategy, hot_ids_from_corpus,
                        list_strategies)
 from repro.configs.base import DPMRConfig
-from repro.core import dpmr
+from repro.core import dpmr, reference
 from repro.data import get_source, sparse_corpus
 from repro.launch.mesh import make_host_mesh
 from repro.runtime.elastic import reshard_dpmr_state
@@ -79,25 +79,6 @@ def _cfg(**kw):
                 learning_rate=1.0, max_hot=32)
     base.update(kw)
     return DPMRConfig(**base)
-
-
-def _dense_lr_oracle(batches, f, lr, iters):
-    """Numpy full-batch GD logistic regression (the ground truth)."""
-    theta = np.zeros(f, np.float32)
-    for _ in range(iters):
-        acc = np.zeros(f, np.float64)
-        nb = 0
-        for b in batches:
-            ids, vals, y = b["ids"], b["vals"], b["labels"]
-            th = theta[np.clip(ids, 0, None)] * (ids >= 0)
-            logits = (th * vals).sum(1)
-            p = 1 / (1 + np.exp(-logits))
-            g = vals * (p - y)[:, None] / ids.shape[0]
-            np.add.at(acc, np.clip(ids, 0, f - 1),
-                      np.where(ids >= 0, g, 0.0))
-            nb += 1
-        theta = theta - lr * (acc / nb).astype(np.float32)
-    return theta
 
 
 # ---------------------------------------------------------------------------
@@ -186,13 +167,9 @@ def test_dense_oracle_agreement_on_accumulate_path(name):
     eng = DPMREngine(cfg, mesh, hot_ids=hot)
     eng.fit(lambda: iter(batches))
     f = dpmr.padded_features(cfg, mesh)
-    oracle = _dense_lr_oracle(batches, f, cfg.learning_rate,
-                              cfg.iterations)
-    theta = np.asarray(eng.state.cold).copy()
-    hids = np.asarray(eng.state.hot_ids)
-    real = hids < 2**31 - 1
-    theta[hids[real]] = np.asarray(eng.state.hot)[real]
-    np.testing.assert_allclose(theta, oracle, atol=2e-4)
+    oracle = reference.gd_iterations(cfg, batches, cfg.iterations, f)
+    theta = reference.engine_table(eng.state)
+    np.testing.assert_allclose(theta, np.asarray(oracle), atol=2e-4)
     # the frozen carry never accumulates residual through fit()
     assert float(jnp.abs(eng.state.strat).sum()) == 0.0
 
@@ -282,7 +259,7 @@ def test_compositions_on_pod_mesh():
     body = """
 import json
 import jax.numpy as jnp, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.api import DPMREngine, get_strategy
 from repro.configs.base import DPMRConfig
 from repro.data import get_source
@@ -293,7 +270,7 @@ src = get_source("zipf_sparse", batch_size=256, num_features=1<<12,
 batches = list(src.iter_batches(limit=3))
 base = dict(num_features=1<<12, max_features_per_sample=16, iterations=2,
             learning_rate=1.0, max_hot=32)
-mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 out = {}
 ref = DPMREngine(DPMRConfig(distribution="a2a", **base), mesh)
 ref.fit(lambda: iter(batches))

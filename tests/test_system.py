@@ -4,7 +4,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.api import DPMREngine, hot_ids_from_corpus
 from repro.configs import ARCH_IDS, SHAPES
 from repro.configs.base import DPMRConfig
@@ -60,7 +59,7 @@ def test_serve_greedy_decode_runs():
     mesh = make_host_mesh(1, 1)
     cfg = registry.smoke_config("yi-6b")
     spec = registry.get_spec("yi-6b")
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = trainer.init_state(spec, cfg, TrainConfig(optimizer="sgd"),
                                    ParallelConfig(), jax.random.PRNGKey(0))
         batch = {"tokens": jnp.ones((2, 8), jnp.int32)}
@@ -116,8 +115,8 @@ def test_hot_sharding_reduces_overflow():
     tail = rng.integers(0, f, size=400).astype(np.int32)
     ids = jnp.asarray(np.concatenate([head, tail]))
 
-    counts = hot_sharding.feature_counts(ids, f)
-    hot = hot_sharding.select_hot(counts, threshold=0.01, max_hot=32)
+    hot = jnp.asarray(hot_sharding.select_hot(ids, threshold=0.01,
+                                              max_hot=32))
     _, _, cold = hot_sharding.split_hot(ids, hot)
 
     r_no = sparse.route_build(ids, p, block, cap)

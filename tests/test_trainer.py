@@ -3,7 +3,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.configs.base import ParallelConfig, TrainConfig
 from repro.data.pipeline import LMDataConfig, LMDataset
 from repro.launch.mesh import make_host_mesh
@@ -27,7 +26,7 @@ def test_microbatch_equivalence():
     outs = {}
     for k in (1, 4):
         cfg, spec, tc, pc, mesh = _setup(micro=k)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = trainer.init_state(spec, cfg, tc, pc,
                                        jax.random.PRNGKey(0))
             step = jax.jit(trainer.make_train_step(spec, cfg, tc, pc, mesh))
@@ -99,7 +98,7 @@ def test_deterministic_data_pipeline():
 
 def test_loss_decreases_over_training():
     cfg, spec, tc, pc, mesh = _setup(arch="granite-8b")
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = trainer.init_state(spec, cfg, tc, pc, jax.random.PRNGKey(0))
         step = jax.jit(trainer.make_train_step(spec, cfg, tc, pc, mesh))
         ds = LMDataset(LMDataConfig(cfg.vocab_size, 32, 8))
