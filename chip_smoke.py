@@ -14,8 +14,10 @@ runs in this one process, through `DPMREngine` and `DPMRServeEngine`.
 
 One chip (the default):
   train      20 `fit_sgd` steps, `kernel_impl="xla"`; loss and overflow of
-             every step, the first step's time (compile + run), the steady
-             time per step and the peak HBM (informational, not metrics)
+             every step, the first step's time (compile + run), the later
+             steps' time per step in the engine's `dpmr.dispatch` and
+             `dpmr.metrics_sync` spans, and the peak HBM (informational,
+             not metrics)
   reference  the same steps through `repro.core.reference` (one dense
              float32 table, no routing); losses and parameters must agree
   pallas     the same steps with `kernel_impl="pallas"` (the sigmoid_grad
@@ -110,22 +112,25 @@ def peak_hbm(label: str) -> None:
 def train(label: str, cfg, mesh, hot, seed: int):
     """STEPS fit_sgd steps from zeros; returns (engine, losses)."""
     from repro.api import DPMREngine, ShardedLoader
+    from repro.runtime import spans
 
     loader = ShardedLoader(make_source(cfg, seed), mesh, prefetch=2)
     engine = DPMREngine(cfg, mesh, hot_ids=hot)
     t0 = time.perf_counter()
     hist = engine.fit_sgd(loader, steps=1)
     first = time.perf_counter() - t0
-    t0 = time.perf_counter()
+    spans.reset()
     hist += engine.fit_sgd(loader, steps=STEPS - 1)
-    steady = (time.perf_counter() - t0) / (STEPS - 1)
+    step = spans.totals()["spans"]
+    steady = sum(step[k]["s"] for k in ("dpmr.dispatch", "dpmr.metrics_sync")
+                 ) / (STEPS - 1)
     for h in hist:
         print(f"[{label}] step {h['step']} loss {h['loss']!r} "
               f"overflow {h['overflow']}", flush=True)
     print(f"[{label}] P={engine.fns.num_shards} capacity="
           f"{engine.fns.capacity} first step (compile + run) {first:.3f} s, "
-          f"compile ~{first - steady:.3f} s, steady {steady * 1e3:.3f} "
-          "ms/step (host clock, informational)", flush=True)
+          f"then {steady * 1e3:.3f} ms/step in dpmr.dispatch + "
+          "dpmr.metrics_sync (host clock, informational)", flush=True)
     overflow = sum(h["overflow"] for h in hist)
     if overflow:
         raise SmokeFailure(f"[{label}] {overflow} features overflowed the "

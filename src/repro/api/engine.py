@@ -53,7 +53,7 @@ from repro.core.dpmr import StepFns
 from repro.data import DataSource, ShardedLoader, get_source
 from repro.data.loader import put_sharded
 from repro.kernels import ops
-from repro.runtime import multiprocess
+from repro.runtime import multiprocess, spans
 
 
 def put_batch(batch: dict, mesh) -> dict:
@@ -143,6 +143,7 @@ class DPMREngine:
         self._fns: dict[int, StepFns] = {}
         self._checkpointers: dict[str, Checkpointer] = {}
         self._loader: ShardedLoader | None = None
+        self._steps = 0      # train_step calls: the step spans' numbers
         self._schedule = dpmr.make_schedule(cfg)
         with jax.set_mesh(mesh):
             self.state = state if state is not None else dpmr.init_state(
@@ -154,6 +155,7 @@ class DPMREngine:
         """Compiled StepFns for a given GLOBAL batch size (LRU-cached)."""
         fns = self._fns.pop(batch_size, None)
         if fns is None:
+            spans.count("dpmr.step_fns_built")
             with jax.set_mesh(self.mesh):
                 fns = dpmr.make_step_fns(
                     self.cfg, self.mesh, batch_size,
@@ -213,12 +215,20 @@ class DPMREngine:
 
     def train_step(self, batch: dict) -> dict:
         """One minibatch update; returns host-side metrics."""
-        fns = self.step_fns(len(batch["labels"]))
-        with jax.set_mesh(self.mesh):
-            self.state, m = fns.train_step(self.state,
-                                           self.put_batch(batch))
-        return {"loss": float(m["loss"]), "accuracy": float(m["accuracy"]),
-                "overflow": int(m["overflow"])}
+        self._steps += 1
+        with spans.step_span("dpmr.train_step", self._steps):
+            with spans.span("dpmr.dispatch"):
+                fns = self.step_fns(len(batch["labels"]))
+                with jax.set_mesh(self.mesh):
+                    self.state, m = fns.train_step(self.state,
+                                                   self.put_batch(batch))
+            with spans.span("dpmr.metrics_sync"):
+                out = {"loss": float(m["loss"]),
+                       "accuracy": float(m["accuracy"]),
+                       "overflow": int(m["overflow"])}
+        spans.count("dpmr.steps")
+        spans.count("dpmr.overflow", out["overflow"])
+        return out
 
     def fit_sgd(self, data, steps: int | None = None, *,
                 spec: dict | None = None) -> list[dict]:

@@ -231,9 +231,11 @@ def _sparse_distribute(ctx, cold_loc, cold_ids, a2a_fn=None):
             x, ctx.axes, 0, 0, tiled=True)
     routing = sparse.route_build(cold_ids, ctx.num_shards, ctx.block_size,
                                  ctx.capacity)
-    req_recv = a2a_fn(routing.req_ids)
+    with jax.named_scope("exchange"):
+        req_recv = a2a_fn(routing.req_ids)
     resp = sparse.owner_apply(req_recv, cold_loc, _owner_base(ctx))
-    resp_back = a2a_fn(resp)
+    with jax.named_scope("exchange"):
+        resp_back = a2a_fn(resp)
     theta_cold = sparse.route_return(routing, resp_back)
     return theta_cold, {"routing": routing, "req_recv": req_recv,
                         "cold_ids": cold_ids, "overflow": routing.overflow}
@@ -265,7 +267,8 @@ class AllToAllStrategy(DistributionStrategy):
 
     def reduce(self, ctx, cold_loc, grads_flat, fwd):
         send = sparse.combine_grads(fwd["routing"], grads_flat)
-        recv = jax.lax.all_to_all(send, ctx.axes, 0, 0, tiled=True)
+        with jax.named_scope("exchange"):
+            recv = jax.lax.all_to_all(send, ctx.axes, 0, 0, tiled=True)
         return _owner_accumulate(ctx, fwd["req_recv"], recv,
                                  jnp.zeros_like(cold_loc),
                                  _owner_base(ctx))

@@ -222,12 +222,13 @@ def _device_fwd(cfg, strategy, ctx, kernel_impl,
                 cold_loc, hot, hot_ids, ids, vals):
     """Stages distribute+restore: returns (theta (B,K), fwd-state, aux)."""
     flat = ids.reshape(-1)
-    hot_slot, is_hot, cold_ids = hot_sharding.split_hot(flat, hot_ids)
+    with jax.named_scope("dpmr.split_hot"):
+        hot_slot, is_hot, cold_ids = hot_sharding.split_hot(flat, hot_ids)
 
-    theta_cold, fwd = strategy.distribute(ctx, cold_loc, cold_ids)
-
-    theta_hot = jnp.where(is_hot, hot[jnp.clip(hot_slot, 0)], 0.0)
-    theta = (theta_cold + theta_hot).reshape(ids.shape)
+    with jax.named_scope("dpmr.distribute"):
+        theta_cold, fwd = strategy.distribute(ctx, cold_loc, cold_ids)
+        theta_hot = jnp.where(is_hot, hot[jnp.clip(hot_slot, 0)], 0.0)
+        theta = (theta_cold + theta_hot).reshape(ids.shape)
     aux = {"hot_slot": hot_slot, "is_hot": is_hot,
            "overflow": fwd["overflow"]}
     return theta, fwd, aux
@@ -246,33 +247,34 @@ def _device_grads(cfg, strategy, ctx, kernel_impl,
     `fwd["accumulate"]` so lossy strategies whose correctness depends on
     the carry advancing (e.g. topk_reduce) can fall back to an exact
     reduce there."""
-    gflat = grads_slot.reshape(-1)
-    if stateful:
-        grad_cold, strat_new = strategy.reduce(
-            ctx, cold_loc, gflat,
-            {**fwd, "carry": strat_loc, "accumulate": accumulating})
-    else:
-        grad_cold = strategy.reduce(ctx, cold_loc, gflat, fwd)
-        strat_new = strat_loc
+    with jax.named_scope("dpmr.reduce"):
+        gflat = grads_slot.reshape(-1)
+        if stateful:
+            grad_cold, strat_new = strategy.reduce(
+                ctx, cold_loc, gflat,
+                {**fwd, "carry": strat_loc, "accumulate": accumulating})
+        else:
+            grad_cold = strategy.reduce(ctx, cold_loc, gflat, fwd)
+            strat_new = strat_loc
 
-    hot_n = jnp.zeros((cfg.max_hot,), jnp.float32)
-    ghot = hot_n.at[jnp.where(aux["is_hot"], aux["hot_slot"],
-                              cfg.max_hot)].add(
-        jnp.where(aux["is_hot"], gflat, 0.0), mode="drop")
-    grad_hot = jax.lax.psum(ghot, ctx.axes)
+        hot_n = jnp.zeros((cfg.max_hot,), jnp.float32)
+        ghot = hot_n.at[jnp.where(aux["is_hot"], aux["hot_slot"],
+                                  cfg.max_hot)].add(
+            jnp.where(aux["is_hot"], gflat, 0.0), mode="drop")
+        grad_hot = jax.lax.psum(ghot, ctx.axes)
     return grad_cold, grad_hot, strat_new
 
 
 def _metrics(axes, probs, labels, nll, overflow):
-    y = labels.astype(jnp.float32)
-    pred = (probs >= 0.5).astype(jnp.float32)
-    acc = jnp.mean((pred == y).astype(jnp.float32))
-    m = {
-        "loss": jax.lax.pmean(jnp.mean(nll), axes),
-        "accuracy": jax.lax.pmean(acc, axes),
-        "overflow": jax.lax.psum(overflow, axes),
-    }
-    return m
+    with jax.named_scope("dpmr.metrics"):
+        y = labels.astype(jnp.float32)
+        pred = (probs >= 0.5).astype(jnp.float32)
+        acc = jnp.mean((pred == y).astype(jnp.float32))
+        return {
+            "loss": jax.lax.pmean(jnp.mean(nll), axes),
+            "accuracy": jax.lax.pmean(acc, axes),
+            "overflow": jax.lax.psum(overflow, axes),
+        }
 
 
 # ---------------------------------------------------------------------------
@@ -345,10 +347,11 @@ def make_step_fns(cfg: DPMRConfig, mesh, batch_size: int,
         theta, fwd, aux = _device_fwd(
             cfg, strategy, ctx, kernel_impl,
             cold_loc, hot, hot_ids, ids, vals)
-        grads_slot, probs, nll = ops.sigmoid_grad(
-            vals, theta, labels, impl=kernel_impl)
-        if cfg.grad_scale == "mean":
-            grads_slot = grads_slot / float(batch_size)
+        with jax.named_scope("dpmr.map"):
+            grads_slot, probs, nll = ops.sigmoid_grad(
+                vals, theta, labels, impl=kernel_impl)
+            if cfg.grad_scale == "mean":
+                grads_slot = grads_slot / float(batch_size)
         grad_cold, grad_hot, strat_new = _device_grads(
             cfg, strategy, ctx, kernel_impl,
             cold_loc, grads_slot, fwd, aux, strat_loc, stateful,
@@ -360,9 +363,11 @@ def make_step_fns(cfg: DPMRConfig, mesh, batch_size: int,
                   strat_loc, ids, vals, labels):
         grad_cold, grad_hot, strat_new, m = _fwd_grads(
             cold_loc, hot, hot_ids, strat_loc, ids, vals, labels)
-        lr = sched(step)
-        cold_new, cold_acc = optimize(cfg, cold_loc, cold_acc, grad_cold, lr)
-        hot_new, hot_acc = optimize(cfg, hot, hot_acc, grad_hot, lr)
+        with jax.named_scope("dpmr.optimize"):
+            lr = sched(step)
+            cold_new, cold_acc = optimize(cfg, cold_loc, cold_acc,
+                                          grad_cold, lr)
+            hot_new, hot_acc = optimize(cfg, hot, hot_acc, grad_hot, lr)
         return (cold_new, hot_new, hot_ids, cold_acc, hot_acc, step + 1,
                 strat_new, m)
 

@@ -22,10 +22,22 @@ labels (B,) int32.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+
+def _scoped(fn):
+    """`fn` traced under a `jax.named_scope` of its own name, so that its
+    ops carry the name in the compiled step whichever strategy calls it."""
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope(fn.__name__):
+            return fn(*args, **kwargs)
+
+    return scoped
 
 
 class Routing(NamedTuple):
@@ -40,6 +52,7 @@ class Routing(NamedTuple):
     overflow: jax.Array      # () int32: dropped unique features (capacity)
 
 
+@_scoped
 def route_build(ids_flat: jax.Array, num_shards: int, block_size: int,
                 cap: int) -> Routing:
     """Build the request plan. ids_flat: (n,) int32 with -1 for padding."""
@@ -84,6 +97,7 @@ def route_build(ids_flat: jax.Array, num_shards: int, block_size: int,
     return Routing(req, order, owner_s, pos_s, keep_s, start_idx_s, overflow)
 
 
+@_scoped
 def route_return(routing: Routing, resp: jax.Array) -> jax.Array:
     """Map responses (P, cap) back to the original slot layout (n,).
 
@@ -102,6 +116,7 @@ def route_return(routing: Routing, resp: jax.Array) -> jax.Array:
     return out.at[routing.order].set(vals_sorted)
 
 
+@_scoped
 def combine_grads(routing: Routing, grads_flat: jax.Array) -> jax.Array:
     """Combiner: sum per-slot grads by feature -> (P, cap) send buffer.
 
@@ -118,6 +133,7 @@ def combine_grads(routing: Routing, grads_flat: jax.Array) -> jax.Array:
     return send.at[scat_owner, routing.pos_s].add(g_sorted, mode="drop")
 
 
+@_scoped
 def owner_apply(req_ids: jax.Array, table_local: jax.Array,
                 base: jax.Array) -> jax.Array:
     """Owner side of distributeParameters: look up requested rows.
@@ -130,6 +146,7 @@ def owner_apply(req_ids: jax.Array, table_local: jax.Array,
     return jnp.where(req_ids >= 0, vals, 0.0)
 
 
+@_scoped
 def owner_accumulate(req_ids: jax.Array, grads: jax.Array,
                      acc_local: jax.Array, base: jax.Array) -> jax.Array:
     """Owner side of the gradient reduce: scatter-add received sums."""

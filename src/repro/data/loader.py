@@ -75,6 +75,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.data.ownership import ShardAssignment, reassign_state
 from repro.data.sources import DataSource
+from repro.runtime import spans
 
 
 def put_sharded(batch: dict, mesh) -> dict:
@@ -386,8 +387,9 @@ class ShardedLoader:
         if self.prefetch <= 0:
             for pos, after in plan:
                 self._check_token(token)
-                batch = self._place(self._load(pos))
+                batch = self._load_placed(pos)
                 self._cursor = after
+                spans.count("loader.batches")
                 yield batch
             return
         yield from self._prefetched(plan, token)
@@ -525,6 +527,10 @@ class ShardedLoader:
             return batch
         raise ValueError(f"unknown placement {self.placement!r}")
 
+    def _load_placed(self, pos: Cursor) -> dict:
+        with spans.span("loader.place", epoch=pos.epoch, step=pos.step):
+            return self._place(self._load(pos))
+
     def _prefetched(self, plan: Iterator[tuple],
                     token: int) -> Iterator[dict]:
         """Background-thread synthesis + placement, bounded-queue delivery.
@@ -550,8 +556,7 @@ class ShardedLoader:
                 for pos, after in plan:
                     if stop.is_set():
                         return
-                    if not offer(("batch", self._place(self._load(pos)),
-                                  after)):
+                    if not offer(("batch", self._load_placed(pos), after)):
                         return
                 offer(("done", None, None))
             except BaseException as e:  # surface in the consumer
@@ -562,13 +567,19 @@ class ShardedLoader:
         thread.start()
         try:
             while True:
-                kind, payload, after = q.get()
+                with spans.span("loader.wait"):
+                    try:
+                        kind, payload, after = q.get_nowait()
+                    except queue.Empty:
+                        spans.count("loader.starved")
+                        kind, payload, after = q.get()
                 if kind == "done":
                     return
                 if kind == "error":
                     raise payload
                 self._check_token(token)
                 self._cursor = after
+                spans.count("loader.batches")
                 yield payload
         finally:
             stop.set()

@@ -22,6 +22,7 @@ Examples:
 from __future__ import annotations
 
 import argparse
+import collections
 import hashlib
 import json
 import logging
@@ -34,7 +35,7 @@ from repro.configs.base import ParallelConfig, TrainConfig
 from repro.data import Cursor, ShardedLoader, get_source
 from repro.launch.mesh import make_host_mesh
 from repro.models import registry
-from repro.runtime import compile_cache
+from repro.runtime import compile_cache, spans
 from repro.runtime.fault_tolerance import PreemptionGuard, StragglerWatchdog
 from repro.train import trainer
 
@@ -55,6 +56,22 @@ def make_loader(args, cfg, mesh=None) -> ShardedLoader:
     return ShardedLoader(source, mesh, placement="device",
                          host_index=0, num_hosts=1,
                          prefetch=args.prefetch)
+
+
+def log_step_spans() -> None:
+    """Host time per step since the last `spans.reset()`, by the
+    program's spans, and its counters."""
+    t = spans.totals()
+    c = collections.Counter(t["counts"])
+    ms = {k: t["spans"].get(k, {"s": 0.0})["s"] / max(c["dpmr.steps"], 1)
+          * 1e3 for k in ("dpmr.dispatch", "dpmr.metrics_sync",
+                          "loader.wait")}
+    log.info("%d steps (%d step fns built, %d batches loaded), per step: "
+             "dispatch %.3f ms, metrics_sync %.3f ms, loader.wait %.3f ms; "
+             "dpmr.overflow %d, loader.starved %d", c["dpmr.steps"],
+             c["dpmr.step_fns_built"], c["loader.batches"],
+             ms["dpmr.dispatch"], ms["dpmr.metrics_sync"],
+             ms["loader.wait"], c["dpmr.overflow"], c["loader.starved"])
 
 
 def sparse_loop(args) -> dict:
@@ -132,7 +149,9 @@ def sparse_loop(args) -> dict:
     history = []
     while int(engine.state.step) < args.steps:
         chunk = min(args.save_every, args.steps - int(engine.state.step))
+        spans.reset()
         history += engine.fit_sgd(loader, steps=chunk)
+        log_step_spans()
         if args.ckpt:
             engine.save(args.ckpt, keep=args.keep,
                         block=not args.async_ckpt)
