@@ -9,6 +9,8 @@ inside a module fixture (the TPU library may be loaded by one process at
 a time, so never at import time); where it cannot be described the tests
 skip.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -17,7 +19,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import DPMRConfig
-from repro.core import dpmr
+from repro.core import dpmr, hot_sharding
 from repro.kernels import segment_sum, select_pack, sigmoid_grad
 
 GiB = 1 << 30
@@ -114,12 +116,38 @@ def _train_step(topo, n_chips: int, impl: str):
         return fns.train_step.lower(state, batch).compile()
 
 
-def test_train_step_one_chip_fits(topo):
+# temp_size_in_bytes of the one-chip step below when `split_hot` was a
+# binary search (searchsorted), read for the described v5e (PERF.md)
+SEARCH_SPLIT_TEMP = 538_870_784
+
+
+@pytest.fixture(scope="module")
+def one_chip_step(topo):
+    return _train_step(topo, 1, "xla")
+
+
+def test_train_step_one_chip_fits(one_chip_step):
     """One chip holds the whole 2^27 table: cold + cold_acc are 1 GiB of
     arguments, and the step's temporaries stay well inside 16 GB."""
-    mem = _train_step(topo, 1, "xla").memory_analysis()
+    mem = one_chip_step.memory_analysis()
     assert 2 * (1 << 27) * 4 <= mem.argument_size_in_bytes < 1.1 * GiB
     assert mem.temp_size_in_bytes < 4 * GiB
+
+
+def test_train_step_split_stores_no_compare(one_chip_step):
+    """The split's (512, 262144) compare stays inside its reduction:
+    the step needs no more temporaries than with the binary search."""
+    mem = one_chip_step.memory_analysis()
+    assert mem.temp_size_in_bytes <= SEARCH_SPLIT_TEMP + 4 * (1 << 20)
+
+
+def test_split_hot_compiles_without_search(topo):
+    """At 262,144 ids against 512 hot ids the split is one fused
+    compare-and-reduce: no loop, no gather."""
+    text = jax.jit(hot_sharding.split_hot).lower(
+        _one(topo, (262144,), jnp.int32),
+        _one(topo, (512,), jnp.int32)).compile().as_text()
+    assert not re.search(r"\s(while|gather)\(", text)
 
 
 def test_train_step_four_chips_exchanges(topo):

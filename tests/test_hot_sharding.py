@@ -121,6 +121,53 @@ def test_split_hot_roundtrips_every_id():
             assert cold[i] == f and slot[i] == -1
 
 
+F_BIG = 1 << 27
+
+
+def _split_oracle(ids, hot_ids):
+    """`split_hot` in numpy: searchsorted (side left), then the match."""
+    pos = np.clip(np.searchsorted(hot_ids, ids, side="left"), 0,
+                  len(hot_ids) - 1)
+    is_hot = (hot_ids[pos] == ids) & (ids >= 0)
+    return (np.where(is_hot, pos, -1).astype(np.int32), is_hot,
+            np.where(is_hot | (ids < 0), -1, ids).astype(np.int32))
+
+
+@pytest.mark.parametrize("kind", ["mixed", "empty", "all_hot"])
+@pytest.mark.parametrize("h", [1, 8, 512, 4096])
+def test_split_hot_matches_searchsorted_oracle(h, kind):
+    """The compare-and-reduce gives the search's outputs bit for bit, at
+    hot sets of 1 to 4096: padding -1, id 0 and the largest id below F, a
+    partly padded hot set with a repeated id, an empty (all INT_MAX) one,
+    and a batch whose every id is hot."""
+    rng = np.random.default_rng(h)
+    real = h - h // 4 if kind != "empty" else 0
+    hot = {0, F_BIG - 1} if real >= 2 else ({0} if real else set())
+    while len(hot) < real:
+        hot.add(int(rng.integers(1, F_BIG - 1)))
+    hot_ids = np.full((h,), int(INT_MAX), np.int32)
+    hot_ids[:real] = np.sort(np.fromiter(hot, np.int64, real))
+    if kind == "mixed" and real >= 4:       # a repeated id: its first slot
+        hot_ids[real // 2] = hot_ids[real // 2 - 1]
+    n = 4096
+    if kind == "all_hot":
+        ids = hot_ids[rng.integers(0, real, n)]
+    else:
+        ids = rng.integers(0, F_BIG, n)
+        pick = rng.random(n)
+        if real:
+            ids = np.where(pick < 0.3, hot_ids[rng.integers(0, real, n)],
+                           ids)
+        ids = np.where(pick > 0.9, -1, ids)
+        ids[:3] = [-1, 0, F_BIG - 1]
+    ids = ids.astype(np.int32)
+    got = [np.asarray(a) for a in
+           hot_sharding.split_hot(jnp.asarray(ids), jnp.asarray(hot_ids))]
+    for g, w in zip(got, _split_oracle(ids, hot_ids)):
+        assert g.dtype == w.dtype and np.array_equal(g, w)
+    assert got[1].all() == (kind == "all_hot")
+
+
 def test_load_imbalance_uniform_vs_skewed():
     # 4 shards x block 2: one id per owner -> perfectly balanced
     even = jnp.asarray([0, 2, 4, 6], jnp.int32)
