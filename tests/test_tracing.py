@@ -334,10 +334,33 @@ def test_readers_are_silent_on_a_program_without_names(tmp_path,
     for name in ("split_hot_ms.train", "distribute_ms.train",
                  "map_ms.train", "reduce_ms.train", "optimize_ms.train",
                  "dispatch_ms.train", "metrics_sync_ms.train",
-                 "loader_queue_wait_ms.train", "loader_place_ms.train"):
+                 "loader_queue_wait_ms.train", "loader_place_ms.train",
+                 "exchange_ms.train", "owner_ms.train"):
         mod = common.load_module("metrics", name)
         assert mod.read(record) is None, name
         assert mod.read({"steps": 8}) is None, name
+
+
+@pytest.mark.parametrize("name,subs,want", [
+    ("exchange_ms.train", {"exchange": 0.5, "owner_apply": 2.0}, 1.0),
+    ("exchange_ms.train", {"owner_apply": 2.0}, None),
+    ("owner_ms.train", {"owner_apply": 2.0, "owner_accumulate": 1.5,
+                        "exchange": 0.5}, 7.0),
+    ("owner_ms.train", {"owner_accumulate": 1.5}, 3.0),
+    ("owner_ms.train", {"exchange": 0.5}, None),
+])
+def test_sub_stage_readers(monkeypatch, name, subs, want):
+    """`exchange_ms.train` and `owner_ms.train` read device ms a step from
+    the sub-stages (already averaged over the chips), and return None
+    where their scopes are absent."""
+    from bench import common
+
+    monkeypatch.setattr(pt, "read_record", lambda record: {
+        "substages": subs} if record.get("trace") else None)
+    mod = common.load_module("metrics", name)
+    got = mod.read({"trace": {"busy_s": 1.0}, "steps": 500})
+    assert got == (None if want is None else pytest.approx(want))
+    assert mod.read({"steps": 500}) is None
 
 
 def test_intervals_helpers():
